@@ -1,9 +1,6 @@
 """Reproduction of "Optimal Complexity in Non-Convex Decentralized Learning
 over Time-Varying Networks" as a production-scale jax system.
 
-Importing :mod:`repro` installs the jax compatibility shims in
-:mod:`repro._compat` (newer mesh API emulated on jax 0.4.x) before any mesh
-or sharding machinery is touched.
+Importing :mod:`repro` has no side effects: subpackages are imported on
+demand, and nothing here touches a device or the jax configuration.
 """
-
-from . import _compat  # noqa: F401  (must run before any mesh use)

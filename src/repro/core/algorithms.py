@@ -179,7 +179,6 @@ def one_peer_mix_ppermute(perm: list, w_peer: float, tree: PyTree,
     perm: static list of (src, dst) node pairs (the matching, both
     directions).  Node axis must be fully sharded over ``axis``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def _mix_shard(x):
@@ -188,8 +187,8 @@ def one_peer_mix_ppermute(perm: list, w_peer: float, tree: PyTree,
 
     def _m(x):
         spec = P(axis, *([None] * (x.ndim - 1)))
-        return shard_map(_mix_shard, mesh=mesh, in_specs=spec,
-                         out_specs=spec)(x)
+        return jax.shard_map(_mix_shard, mesh=mesh, in_specs=spec,
+                             out_specs=spec)(x)
 
     return jax.tree.map(_m, tree)
 

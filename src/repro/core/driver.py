@@ -76,7 +76,7 @@ def stage(schedule, *, wps: int, impl: str = "dense", total: int | None = None,
     return StagedGossip("dense", arrays, period, wps)
 
 
-def bind_step(staged: StagedGossip, core_step):
+def bind_step(staged: StagedGossip, core_step, *, donate: bool = False):
     """Jit ``core_step`` against the staged gossip.
 
     ``core_step(state, extra, gossip, t)`` — ``extra`` is the per-step
@@ -84,12 +84,20 @@ def bind_step(staged: StagedGossip, core_step):
     step's gathered ``(wps, n, n)`` window.  Auto: ``gossip`` is the plan
     tensors and ``t`` the start round (static when the plan dispatch is).
 
+    ``donate=True`` donates the state argument, so the step updates it in
+    place and input and output state are never both live — what lets a
+    full-width model fit one device.  The caller must then own the state
+    outright: the arrays passed in are deleted by the call, so no other
+    reference to them (a user's ``x0``, a second leaf aliasing the same
+    buffer) may be used again.
+
     Returns ``step(state, extra, t) -> (state, out)`` with the staged
     arrays closed over.
     """
+    donate_argnums = (0,) if donate else ()
     if staged.impl == "auto":
-        fn = (jax.jit(core_step, static_argnums=3) if staged.static_t
-              else jax.jit(core_step))
+        fn = jax.jit(core_step, donate_argnums=donate_argnums,
+                     static_argnums=(3,) if staged.static_t else ())
         return lambda state, extra, t: fn(state, extra, staged.arrays, t)
 
     wps, period = staged.wps, staged.period
@@ -98,7 +106,7 @@ def bind_step(staged: StagedGossip, core_step):
         idx = (t + jnp.arange(wps)) % period
         return core_step(state, extra, jnp.take(Ws_all, idx, axis=0), t)
 
-    fn = jax.jit(gathered)
+    fn = jax.jit(gathered, donate_argnums=donate_argnums)
     return lambda state, extra, t: fn(state, extra, staged.arrays, t)
 
 

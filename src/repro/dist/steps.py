@@ -196,9 +196,11 @@ def make_train_step(model, cfg, *, algo: str = "mc_dsgt", gamma: float,
         return jnp.mean(losses), grads
 
     def init_state(key, n: int, dtype) -> TrainState:
+        # every leaf gets a buffer of its own: warm_start and the driver's
+        # step donate the state, and one buffer cannot be donated twice
         params = model.init(key, dtype)
         x = alg.broadcast_nodes(params, n)
-        aux = jax.tree.map(
+        aux = lambda: jax.tree.map(
             lambda l: jnp.zeros(l.shape, aux_dtype or l.dtype), x)
         opt = local_opt.init(x) if local_opt is not None else None
         res = (compress.init_residual(x, rule.uses_tracker, dtype=aux_dtype)
@@ -209,10 +211,12 @@ def make_train_step(model, cfg, *, algo: str = "mc_dsgt", gamma: float,
             # stream seeds with x⁰ (zero correction for the first ``delay``
             # steps under broadcast-identical init); the tracker stream is
             # re-seeded with h⁰ by warm_start.
-            hq = (tuple(aux for _ in range(rule.delay))
+            hq = (tuple(aux() for _ in range(rule.delay))
                   if rule.uses_tracker else None)
-            buf = (tuple(x for _ in range(rule.delay)), hq)
-        return TrainState(x=x, h=aux, g_prev=aux, step=jnp.zeros((), jnp.int32),
+            buf = (tuple(jax.tree.map(jnp.copy, x)
+                         for _ in range(rule.delay)), hq)
+        return TrainState(x=x, h=aux(), g_prev=aux(),
+                          step=jnp.zeros((), jnp.int32),
                           opt=opt, res=res, buf=buf)
 
     # Bind the engine's abstract ops to this runtime: the selected gossip
@@ -289,7 +293,12 @@ def make_train_step(model, cfg, *, algo: str = "mc_dsgt", gamma: float,
     else:
         def step(state: TrainState, batch, weights):
             return core(state, batch, weights, 0)
-    return init_state, jax.jit(warm_start), step
+    # warm_start donates its input state (always a fresh init_state): at
+    # full width, input and output state would not both fit one device.
+    # keep_unused: h/g_prev are overwritten, not read, and an unused input
+    # would otherwise be pruned and stay live instead of being donated
+    return (init_state,
+            jax.jit(warm_start, donate_argnums=0, keep_unused=True), step)
 
 
 def make_prefill_step(model, cfg):

@@ -22,6 +22,7 @@ declared output, runs, and returns a :class:`Result`.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -470,11 +471,11 @@ def _run_arch(built: Built, *, quiet: bool = False) -> Result:
         plan=built.plan,
         static_t=(rs.gossip_impl == "auto"
                   and train_step.gossip_dispatch == "static"))
-    if rs.gossip_impl == "auto":
-        step_fn = driver.bind_step(staged, train_step)
-    else:
-        step_fn = driver.bind_step(
-            staged, lambda state, batch, W, t: train_step(state, batch, W))
+    core = (train_step if rs.gossip_impl == "auto"
+            else lambda state, batch, W, t: train_step(state, batch, W))
+    # the state is this run's own (fresh init or restore), so the step may
+    # donate it: a full-width model then fits one device
+    step_fn = driver.bind_step(staged, core, donate=True)
 
     def record(k, t, state, out, dt):
         if built.obs is not None:
@@ -484,6 +485,11 @@ def _run_arch(built: Built, *, quiet: bool = False) -> Result:
                   if telem is not None else None)
         if k % rs.log_every != 0:
             return None
+        # on an async device ``dt`` is the dispatch only; ``ready`` is the
+        # host clock once the step has finished, so differences of it
+        # between logged steps are true step times
+        jax.block_until_ready((state, out))
+        ready = time.perf_counter()
         loss = float(out["loss"])
         ce = (tl["consensus"] if tl is not None
               else sim_telemetry.consensus_distance(state.x))
@@ -496,7 +502,7 @@ def _run_arch(built: Built, *, quiet: bool = False) -> Result:
         con.print(f"step {k:5d}  T={t:6d}  loss {loss:.4f}  "
                   f"consensus {ce:.3e}{extra}  {dt:.2f}s")
         return {"step": k, "loss": loss, "consensus": ce,
-                "sec": round(dt, 3)}
+                "sec": round(dt, 3), "ready": ready}
 
     state, history = driver.run_loop(
         step_fn, state, steps=rs.steps, wps=built.wps, period=staged.period,

@@ -21,12 +21,18 @@ from .interpret import resolve_interpret
 
 
 def _kernel(w_ref, x_ref, o_ref, *, rounds):
-    w = w_ref[...]                # (R, n, n)
     x = x_ref[...].astype(jnp.float32)  # (n, bd)
 
     def body(r, acc):
+        # index the (R, n, n) ref, not a loaded value: Mosaic lowers a
+        # dynamic ref load but not a dynamic_slice of a VMEM value.
+        # HIGHEST: the MXU's default f32 matmul rounds through bf16, which
+        # would cost the mixed parameters ~8 mantissa bits per round; the
+        # n x n matmul is tiny next to streaming x, so full precision is
+        # nearly free
         return jax.lax.dot_general(
-            w[r].astype(jnp.float32), acc, (((1,), (0,)), ((), ())),
+            w_ref[r].astype(jnp.float32), acc, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     out = jax.lax.fori_loop(0, rounds, body, x)

@@ -38,7 +38,6 @@ from .interpret import resolve_interpret
 
 def _kernel(w_ref, x_ref, r_ref, o_ref, ro_ref, *, rounds, scheme, group,
             error_feedback):
-    w = w_ref[...]                            # (R, n, n), VMEM-resident
     x = x_ref[...].astype(jnp.float32)        # (n, bd)
     res = r_ref[...].astype(jnp.float32)      # (n, bd)
 
@@ -49,8 +48,10 @@ def _kernel(w_ref, x_ref, r_ref, o_ref, ro_ref, *, rounds, scheme, group,
                                                group=group)
         if error_feedback:  # static: selects the traced graph, not a cond
             rs = err
+        # w_ref[r] and HIGHEST: see gossip_matmul._kernel
         e = jax.lax.dot_general(
-            w[r].astype(jnp.float32), deq, (((1,), (0,)), ((), ())),
+            w_ref[r].astype(jnp.float32), deq, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         return e, rs
 

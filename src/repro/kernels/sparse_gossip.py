@@ -36,7 +36,10 @@ def _kernel(seg_ref, w_ref, xs_ref, xd_ref, o_ref, *, num_segments):
     contrib = w[:, None] * (xs - xd)          # (be, bd)
     ids = jax.lax.broadcasted_iota(jnp.int32, (num_segments, seg.shape[0]), 0)
     onehot = (ids == seg[None, :]).astype(jnp.float32)  # (S, be)
+    # HIGHEST: at the MXU's default precision contrib would round through
+    # bf16 (see gossip_matmul._kernel)
     acc = jax.lax.dot_general(onehot, contrib, (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
     @pl.when(e == 0)

@@ -151,17 +151,8 @@ def _probe_cfg(cfg, k_units: int):
     return dataclasses.replace(cfg, **repl)
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on newer jax, a one-element
-    list of dicts on 0.4.x — normalize to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _costs_of(compiled) -> dict:
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = parse_collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -195,7 +186,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     t1 = time.time()
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll_scan = parse_collective_bytes(compiled.as_text())
 
     units, rem = cfg.units_and_rem

@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 
 from repro import exp
+from repro.launch.compile_cache import enable_compile_cache
 
 # flag dest -> dotted ExperimentSpec field (same contract as launch.train:
 # argparse.SUPPRESS keeps unset flags out of the namespace, so the
@@ -145,6 +146,7 @@ def main(argv=None):
     if getattr(args, "dump_config", False):
         print(exp.to_json(spec, elide_defaults=False))
         return spec
+    enable_compile_cache()
     return exp.run(spec, quiet=args.quiet).serve
 
 

@@ -39,6 +39,7 @@ from __future__ import annotations
 import argparse
 
 from repro import exp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.exp import make_weight_schedule  # noqa: F401  (legacy import site)
 
 # flag dest -> dotted ExperimentSpec field.  This mapping IS the CLI's
@@ -249,6 +250,7 @@ def main(argv=None):
     if getattr(args, "dump_config", False):
         print(exp.to_json(spec, elide_defaults=False))
         return spec
+    enable_compile_cache()
     return exp.run(spec, quiet=args.quiet).history
 
 

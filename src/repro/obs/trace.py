@@ -22,6 +22,7 @@ from __future__ import annotations
 import time
 
 import jax
+from jax.extend import core as jex_core
 
 PHASES = ("data", "step", "telemetry", "checkpoint")
 
@@ -57,7 +58,7 @@ def mix_depends_on_grad(jaxpr) -> bool:
     tainted: set = set()
     for eqn in closed.eqns:
         scopes = _eqn_scopes(eqn)
-        consumes = any(not isinstance(v, jax.core.Literal) and v in tainted
+        consumes = any(not isinstance(v, jex_core.Literal) and v in tainted
                        for v in eqn.invars)
         if "obs_mix" in scopes and consumes:
             return True
