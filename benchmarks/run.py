@@ -787,9 +787,11 @@ def bench_obs(quick: bool) -> None:
     ``obs_run_overhead`` — steady-state per-step wall time of the shared
         driver loop on the quickstart workload (logreg d=64 m=256, 16
         nodes, mc_dsgt R=4 over the theorem-3 sun schedule) at three
-        observability levels: ``bare`` (no obs), ``injit`` (the in-jit
-        metric scalars only), and ``full`` (ObsRecorder + phase tracer +
-        gap tracker + JSONL sink at every=10).  The loop is pre-compiled
+        observability levels: ``bare`` (no recorder; the loop's
+        always-on ``data``/``dispatch`` spans still run, since no run is
+        without them), ``injit`` (the in-jit metric scalars only), and
+        ``full`` (ObsRecorder + its span tracer + gap tracker + JSONL sink
+        at every=10).  The loop is pre-compiled
         and timed over interleaved repetitions (median), so compile and
         dataset costs never enter — unlike wall-clocking ``exp.run``,
         which re-jits per call and drowns a us-scale delta in ~1s of

@@ -53,38 +53,12 @@ from repro import configs, exp  # noqa: E402
 from repro.dist import sharding as shd, steps as dsteps  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.obs.trace import compile_counts  # noqa: E402
 
 ARCH = "qwen1.5-0.5b"
 # the largest step the compiler fits in one v5e's HBM at full width with
 # two f32 node copies (x, h, g_prev) and the state donated
 TRAIN = dict(nodes=2, steps=5, R=1, seq=128)
-
-_COMPILE = {"sec": 0.0, "cache_hits": 0, "cache_misses": 0}
-
-
-def _watch_compiles() -> None:
-    """Accumulate JAX's own compile durations and cache counters into
-    ``_COMPILE`` (registered once per process)."""
-    if _COMPILE.get("watching"):
-        return
-    _COMPILE["watching"] = True
-    compile_events = ("/jax/core/compile/jaxpr_trace_duration",
-                      "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                      "/jax/core/compile/backend_compile_duration")
-
-    def on_duration(event, sec, **kw):
-        if event in compile_events:
-            _COMPILE["sec"] += sec
-
-    def on_event(event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            _COMPILE["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            _COMPILE["cache_misses"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-
 
 def _check(ok: bool, what: str) -> None:
     if not ok:
@@ -108,9 +82,8 @@ def _peak_bytes():
 def train_phase(preset: str, *, nodes: int, steps: int, R: int,
                 seq: int) -> dict:
     """``exp.run`` of the main path; raises if any check fails."""
-    _watch_compiles()
     spec = _spec(preset, nodes=nodes, steps=steps, R=R, seq=seq)
-    c0 = dict(_COMPILE)
+    c0 = compile_counts()
     t0 = time.perf_counter()
     res = exp.run(spec, quiet=True)
     state = jax.block_until_ready(res.state)
@@ -143,15 +116,16 @@ def train_phase(preset: str, *, nodes: int, steps: int, R: int,
     _check(scale > 0 and gap <= 1e-4 * scale,
            f"tracker mean gap {gap} at scale {scale}")
 
+    c1 = compile_counts()
     return {"phase": "train", "arch": built.cfg.name, "preset": preset,
             "params_per_node": params, "nodes": nodes, "R": R, "seq": seq,
             "batch": spec.data.batch, "steps": steps,
             "plan_kinds": sorted(set(built.plan.kinds)),
             "losses": losses, "first_step_s": ready[0] - t0,
             "step_s": step_s, "wall_s": wall,
-            "compile_s": _COMPILE["sec"] - c0["sec"],
-            "cache_hits": _COMPILE["cache_hits"] - c0["cache_hits"],
-            "cache_misses": _COMPILE["cache_misses"] - c0["cache_misses"],
+            "compile_s": c1["compile_s"] - c0["compile_s"],
+            "cache_hits": c1["cache_hits"] - c0["cache_hits"],
+            "cache_misses": c1["cache_misses"] - c0["cache_misses"],
             "tracker_gap": gap, "tracker_scale": scale,
             "peak_bytes_in_use": _peak_bytes()}
 
@@ -161,7 +135,6 @@ def gossip_phase(preset: str, *, require_kernel: bool, n: int = 4,
     """The fused gossip kernel at one MLP matrix's width vs the reference.
     ``require_kernel``: the compiled HLO must hold the Mosaic kernel (off
     only where the kernel runs in interpret mode, on the CPU)."""
-    _watch_compiles()
     cfg = configs.get(ARCH)
     if preset == "reduced":
         cfg = cfg.reduced()
@@ -250,7 +223,6 @@ def four_chip_programs(preset: str, mesh, *, seq: int, R: int):
 
 def four_chip_phase(preset: str, devices, *, seq: int, R: int) -> dict:
     """One sharded step, auto (collective-permute) vs dense, on 4 devices."""
-    _watch_compiles()
     _check(len(devices) == 4, f"{len(devices)} devices")
     mesh = Mesh(np.asarray(devices), ("data",))
     key = jax.random.key(0)
@@ -259,7 +231,7 @@ def four_chip_phase(preset: str, devices, *, seq: int, R: int) -> dict:
     with jax.default_matmul_precision("highest"):
         progs, args = four_chip_programs(preset, mesh, seq=seq, R=R)
         b0, b1 = args["batches"]
-        c0 = _COMPILE["sec"]
+        c0 = compile_counts()["compile_s"]
         t0 = time.perf_counter()
         state = progs["warm"](progs["init"](key), b0)
         auto = progs["auto"].lower(state, b1, args["tensors"]).compile()
@@ -294,7 +266,8 @@ def four_chip_phase(preset: str, devices, *, seq: int, R: int) -> dict:
             "seq": seq, "loss_auto": la, "loss_dense": ld,
             "x_max_abs_diff": err, "x_scale": scale,
             "collective_permute_ops": hlo.count("collective-permute"),
-            "setup_s": setup, "compile_s": _COMPILE["sec"] - c0}
+            "setup_s": setup,
+            "compile_s": compile_counts()["compile_s"] - c0}
 
 
 def main(argv=None) -> int:

@@ -15,13 +15,13 @@ transfer.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import time
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ..obs import trace as obs_trace
 
 PyTree = Any
 
@@ -148,33 +148,36 @@ def run_loop(step, state, *, steps: int, wps: int, period: int,
     appended to the history.  ``save_fn(path, state, step)`` runs every
     ``checkpoint_every`` steps and once at the end.
 
-    ``tracer`` is an optional :class:`repro.obs.trace.Tracer`: each loop
-    phase (``data`` = extra_fn, ``step`` = the jitted step dispatch,
-    ``telemetry`` = the record hook, ``checkpoint`` = save_fn) runs inside
-    a wall-clock span + ``jax.profiler.TraceAnnotation``.
+    Every iteration runs under ``tracer.step(k)`` (a
+    ``jax.profiler.StepTraceAnnotation``) and each phase in a span of
+    :mod:`repro.obs.trace`: ``data`` = extra_fn, ``dispatch`` = the jitted
+    step call, ``record`` = the record hook (``dt`` is the dispatch
+    span's duration), ``checkpoint`` = save_fn.  ``tracer`` is the run's
+    :class:`repro.obs.trace.Tracer`; without one the loop makes its own,
+    so the spans are always recorded.
     """
-    span = (tracer.span if tracer is not None
-            else (lambda phase: contextlib.nullcontext()))
+    if tracer is None:
+        tracer = obs_trace.Tracer()
+    span = tracer.span
     history = []
     t = start_step * wps
     last = start_step + steps - 1
     for k in range(start_step, start_step + steps):
-        with span("data"):
-            extra = extra_fn(k) if extra_fn is not None else None
-        t0 = time.time()
-        with span("step"):
-            state, out = step(state, extra, t % period)
-        dt = time.time() - t0
-        t += wps
-        if record is not None:
-            with span("telemetry"):
-                rec = record(k, t, state, out, dt)
-            if rec is not None:
-                history.append(rec)
-        if checkpoint and save_fn is not None and \
-                (k + 1) % checkpoint_every == 0 and k != last:
-            with span("checkpoint"):
-                save_fn(checkpoint, state, k + 1)
+        with tracer.step(k):
+            with span("data"):
+                extra = extra_fn(k) if extra_fn is not None else None
+            with span("dispatch") as d:
+                state, out = step(state, extra, t % period)
+            t += wps
+            if record is not None:
+                with span("record"):
+                    rec = record(k, t, state, out, d.end - d.start)
+                if rec is not None:
+                    history.append(rec)
+            if checkpoint and save_fn is not None and \
+                    (k + 1) % checkpoint_every == 0 and k != last:
+                with span("checkpoint"):
+                    save_fn(checkpoint, state, k + 1)
     if checkpoint and save_fn is not None:
         with span("checkpoint"):
             save_fn(checkpoint, state, start_step + steps)
@@ -205,8 +208,9 @@ def run_algorithm(algo, x0: PyTree, grad_fn, weight_schedule, num_steps: int,
 
     ``obs`` names in-jit metric scalars (:data:`repro.core.engine.
     OBS_METRICS`) to compute inside the step; they arrive at the record
-    hook as ``out["obs"]`` device scalars.  ``tracer`` adds per-phase
-    wall-clock spans to the loop (see :func:`run_loop`).
+    hook as ``out["obs"]`` device scalars.  ``tracer`` is the
+    :class:`repro.obs.trace.Tracer` the loop's spans go to (see
+    :func:`run_loop`).
 
     Returns (final_state, history) where history records ``eval_fn`` of the
     node-mean model x̄ every ``eval_every`` steps (plus the final step),
@@ -246,7 +250,9 @@ def run_algorithm(algo, x0: PyTree, grad_fn, weight_schedule, num_steps: int,
 
     def record(k, t, state, out, dt):
         if telemetry is not None:
-            telemetry.record(k, t, state, out, dt)
+            # inside run_loop's ``record`` span, on its tracer
+            with obs_trace.span("record.telemetry"):
+                telemetry.record(k, t, state, out, dt)
         if eval_fn is None:
             return None
         if k % eval_every == 0 or k == num_steps - 1:

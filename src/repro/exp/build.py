@@ -22,7 +22,6 @@ declared output, runs, and returns a :class:`Result`.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -287,7 +286,7 @@ def build(spec: ExperimentSpec) -> Built:
     built = Built(spec=spec, rule=rule, wps=wps, horizon=horizon,
                   schedule=sched, plan=plan, fault_models=fault_models,
                   local_opt=registry.build_local_opt(al.local_opt),
-                  telemetry=telem)
+                  telemetry=telem, tracer=obs_trace.Tracer())
     if spec.obs.enabled:
         _build_obs(built)
 
@@ -346,15 +345,15 @@ def _effective_beta(sched, period: int, cap: int = 64) -> float:
 
 
 def _build_obs(built: Built) -> None:
-    """Attach the repro.obs bundle to a Built: the event sink, the phase
-    tracer, the optimality-gap tracker for this spec's cell, the optional
-    profiler, and the :class:`~repro.obs.metrics.ObsRecorder` tying them
-    together (chaining the existing TelemetryRecorder when the scenario
-    has one, instead of replacing it)."""
+    """Attach the repro.obs bundle to a Built: the event sink, the
+    optimality-gap tracker for this spec's cell, the optional profiler
+    (driven by the run's span tracer), and the
+    :class:`~repro.obs.metrics.ObsRecorder` tying them together with the
+    tracer (chaining the existing TelemetryRecorder when the scenario has
+    one, instead of replacing it)."""
     spec = built.spec
     rs, al, o = spec.run, spec.algorithm, spec.obs
     built.obs_names = registry.resolve_obs_names(o.names, built.rule)
-    built.tracer = obs_trace.Tracer(annotate=bool(o.profile_dir))
     cell = obs_optimality.cell_key(al.name, spec.topology.kind,
                                    registry.channel_label(spec.channel))
     gap = obs_optimality.GapTracker(
@@ -363,6 +362,7 @@ def _build_obs(built: Built) -> None:
         bound=o.bound)
     profiler = (obs_trace.Profiler(o.profile_dir, o.profile_steps)
                 if o.profile_dir else None)
+    built.tracer.profiler = profiler
     from .spec import spec_hash
     meta = {"name": f"{al.name} on {spec.topology.kind}",
             "spec_hash": spec_hash(spec), "cell": cell,
@@ -397,8 +397,6 @@ def run(spec: ExperimentSpec, *, quiet: bool = False) -> Result:
         mf.write_manifest(spec.run.telemetry, spec, realized=built.realized)
     if spec.obs.metrics:
         mf.write_manifest(spec.obs.metrics, spec, realized=built.realized)
-    if built.obs is not None and built.obs.profiler is not None:
-        built.obs.profiler.start()
     try:
         if spec.model.kind == "arch":
             res = _run_arch(built, quiet=quiet)
@@ -477,32 +475,36 @@ def _run_arch(built: Built, *, quiet: bool = False) -> Result:
     # donate it: a full-width model then fits one device
     step_fn = driver.bind_step(staged, core, donate=True)
 
+    span = built.tracer.span
+    recorder = built.obs if built.obs is not None else telem
+
     def record(k, t, state, out, dt):
-        if built.obs is not None:
-            tl = built.obs.record(k, t, state, out, dt)
-        else:
-            tl = (telem.record(k, t, state, out, dt)
-                  if telem is not None else None)
+        tl = None
+        if recorder is not None:
+            with span("record.telemetry"):
+                tl = recorder.record(k, t, state, out, dt)
         if k % rs.log_every != 0:
             return None
         # on an async device ``dt`` is the dispatch only; ``ready`` is the
-        # host clock once the step has finished, so differences of it
-        # between logged steps are true step times
-        jax.block_until_ready((state, out))
-        ready = time.perf_counter()
-        loss = float(out["loss"])
-        ce = (tl["consensus"] if tl is not None
-              else sim_telemetry.consensus_distance(state.x))
-        extra = ""
-        if tl is not None:
-            ed = tl["eff_diameter"]
-            gap = tl["spectral_gap"]
-            extra = (f"  gap {gap if gap is not None else float('nan'):.3f}"
-                     f"  eff_diam {ed if ed is not None else '-'}")
-        con.print(f"step {k:5d}  T={t:6d}  loss {loss:.4f}  "
-                  f"consensus {ce:.3e}{extra}  {dt:.2f}s")
-        return {"step": k, "loss": loss, "consensus": ce,
-                "sec": round(dt, 3), "ready": ready}
+        # host clock once the step has finished (the sync span's end), so
+        # differences of it between logged steps are true step times
+        with span("record.sync") as sync:
+            jax.block_until_ready((state, out))
+        with span("record.readback"):
+            loss = float(out["loss"])
+            ce = (tl["consensus"] if tl is not None
+                  else sim_telemetry.consensus_distance(state.x))
+            extra = ""
+            if tl is not None:
+                ed = tl["eff_diameter"]
+                gap = tl["spectral_gap"]
+                extra = (f"  gap "
+                         f"{gap if gap is not None else float('nan'):.3f}"
+                         f"  eff_diam {ed if ed is not None else '-'}")
+            con.print(f"step {k:5d}  T={t:6d}  loss {loss:.4f}  "
+                      f"consensus {ce:.3e}{extra}  {dt:.2f}s")
+            return {"step": k, "loss": loss, "consensus": ce,
+                    "sec": round(dt, 3), "ready": sync.end}
 
     state, history = driver.run_loop(
         step_fn, state, steps=rs.steps, wps=built.wps, period=staged.period,

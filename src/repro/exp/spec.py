@@ -161,15 +161,15 @@ class RunSpec:
 @dataclasses.dataclass(frozen=True)
 class ObsSpec:
     """Observability (:mod:`repro.obs`): in-jit step metrics into an event
-    log, phase tracing, and optimality-gap tracking.  Off by default —
+    log, the span table, and optimality-gap tracking.  Off by default —
     enabled when ``metrics`` (the JSONL event-log path) or ``profile_dir``
     is set.  ``names`` selects engine metrics (``'auto'`` = the update
     rule's default set, or a comma-separated subset of
     :data:`repro.obs.metrics.OBS_METRICS`); ``every`` is the host flush
     batch (device scalars cross the host boundary once per ``every``
     steps); ``sink`` is a :data:`repro.exp.registry.SINKS` key;
-    ``profile_dir``/``profile_steps`` dump a jax profiler trace of the
-    first N steps; ``bound`` names the lower-bound reference the gap is
+    ``profile_dir``/``profile_steps`` dump a jax profiler trace of N
+    steps, opened once a step has run without compiling; ``bound`` names the lower-bound reference the gap is
     measured against (:data:`repro.obs.optimality.BOUNDS`)."""
 
     metrics: Optional[str] = None

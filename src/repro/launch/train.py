@@ -217,8 +217,9 @@ def build_parser() -> argparse.ArgumentParser:
                          f"--metrics (of: {', '.join(exp.OBS_METRICS)}); "
                          "'auto' = the update rule's default set")
     ap.add_argument("--profile-dir", metavar="DIR",
-                    help="dump a jax profiler trace of the first "
-                         "--profile-steps steps into DIR")
+                    help="dump a jax profiler trace of --profile-steps "
+                         "steps into DIR, opened once a step has run "
+                         "without compiling")
     ap.add_argument("--profile-steps", type=int)
     ap.add_argument("--quiet", action="store_true", default=False,
                     help="suppress progress output (event-log/telemetry "

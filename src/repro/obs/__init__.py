@@ -11,10 +11,11 @@ layer that lets the repo see its own complexity:
   :mod:`repro.core.engine` for BOTH runtimes) and flushes them host-side
   every ``every`` steps, so observation adds no device syncs to the hot
   path;
-* :mod:`repro.obs.trace` — per-phase wall-clock spans
-  (data/step/telemetry/checkpoint) wrapping
-  ``jax.profiler.TraceAnnotation``, plus the opt-in ``--profile-dir``
-  N-step jax profiler trace;
+* :mod:`repro.obs.trace` — the training loop's always-on spans
+  (data/dispatch/record/record.*/checkpoint) in a process-wide ring
+  buffer, each also a ``jax.profiler.TraceAnnotation``; the process's
+  compile counter, crediting each compile to its span and step; and the
+  opt-in ``--profile-dir`` jax profiler trace of steady steps;
 * :mod:`repro.obs.optimality` — online optimality-gap tracking of the
   measured ||∇f||² trajectory against the paper's lower bound
   (:mod:`repro.core.lower_bound`) per (algorithm x topology-class x
@@ -43,9 +44,12 @@ from .metrics import (  # noqa: F401
 )
 from .optimality import GapTracker, cell_key, theoretical_floor  # noqa: F401
 from .trace import (  # noqa: F401
-    PHASES,
+    SPANS,
     Profiler,
+    Span,
     Tracer,
+    compile_counts,
     mix_depends_on_grad,
     overlap_report,
+    spans,
 )

@@ -16,7 +16,7 @@ Event-log schema (one JSON object per line)::
     {"event": "meta", ...}      run header (spec hash, algo, n, cell, ...)
     {"event": "step", ...}      per-step metrics (see EVENT_FIELDS)
     {"event": "eval", ...}      eval_fn points (k, t, value)
-    {"event": "summary", ...}   end-of-run phase totals + optimality gap
+    {"event": "summary", ...}   end-of-run span statistics + optimality gap
 """
 
 from __future__ import annotations
@@ -56,8 +56,11 @@ EVENT_FIELDS = {
     "sec": "wall-clock seconds of the step dispatch",
     "loss": "runtime scalar loss when the step reports one",
     **OBS_METRICS,
-    "phases": "wall-clock seconds per driver phase since the previous "
-              "record (data/step/telemetry/checkpoint)",
+    "phases": "wall-clock seconds per driver span closed since the "
+              "previous record (data/dispatch/record/record.*/checkpoint)",
+    "compiles": "backend compiles (programs compiled or loaded from the "
+                "persistent cache) in the spans closed since the previous "
+                "record; present only when non-zero",
     "spectral_gap": "realized-window mixing contraction (from the chained "
                     "TelemetryRecorder, when present)",
     "eff_diameter": "realized-window effective diameter (chained "
@@ -207,7 +210,8 @@ class ObsRecorder:
     #
     # The hot path appends raw tuples; the event dicts are built at drain
     # time (in the flusher thread under ``background=True``):
-    #   ("step", k, t, dt, tl, phases, device)   device = {loss?, obs?}
+    #   ("step", k, t, dt, tl, phases, compiles, device)
+    #                                           device = {loss?, obs?}
     #   ("eval", k, t, value)
 
     def record(self, k: int, t: int, state: Any, out: Any,
@@ -215,14 +219,15 @@ class ObsRecorder:
         tl = None
         if self.telemetry is not None:
             tl = self.telemetry.record(k, t, state, out, dt)
-        phases = self.tracer.drain() if self.tracer is not None else None
+        phases = compiles = None
+        if self.tracer is not None:
+            phases = self.tracer.drain()
+            compiles = self.tracer.drain_compiles()
         device = None
         if type(out) is dict:
             device = {kk: out[kk] for kk in ("loss", "obs") if kk in out
                       and out[kk] is not None}
-        self._buf.append(("step", k, t, dt, tl, phases, device))
-        if self.profiler is not None:
-            self.profiler.maybe_stop(k)
+        self._buf.append(("step", k, t, dt, tl, phases, compiles, device))
         if len(self._buf) >= self.every:
             self.flush()
         return tl
@@ -267,7 +272,7 @@ class ObsRecorder:
         # device scalar into a single array (one jitted call — op-by-op
         # jnp.stack would dispatch per element) when the dtypes allow it;
         # 50 tiny per-leaf copies cost ~10x one (50,) copy.
-        devs = [e[6] for e in buf if e[0] == "step" and e[6] is not None]
+        devs = [e[7] for e in buf if e[0] == "step" and e[7] is not None]
         leaves, treedef = jax.tree.flatten(devs)
         try:
             flat = jax.device_get(_pack(leaves)) if leaves else []
@@ -281,7 +286,7 @@ class ObsRecorder:
                 base = {"event": "eval", "step": int(k), "t": int(t),
                         "value": value}
             else:
-                _, k, t, dt, tl, phases, device = entry
+                _, k, t, dt, tl, phases, compiles, device = entry
                 base = {"event": "step", "step": int(k), "t": int(t),
                         "sec": round(float(dt), 6)}
                 if tl:
@@ -290,6 +295,8 @@ class ObsRecorder:
                 if phases:
                     base["phases"] = {p: round(v, 6)
                                       for p, v in phases.items()}
+                if compiles:
+                    base["compiles"] = compiles
                 if device is not None:
                     got = next(host_iter)
                     if "loss" in got:

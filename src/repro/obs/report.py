@@ -1,7 +1,8 @@
 """Render a run summary from a JSONL event log.
 
 ``python -m repro.obs.report <log.jsonl>`` prints the run header, the
-per-phase wall-clock table, per-metric stats with a unicode sparkline of
+driver's span tree (self time, median and max per step, the step of the
+max, and the steps that compiled), per-metric stats with a unicode sparkline of
 the series, and the optimality-gap section (measured best ||grad f||^2 vs
 the paper's lower-bound floor for the run's cell).  Everything is computed
 from the log alone — no jax, no re-execution — so it works on logs shipped
@@ -55,6 +56,45 @@ def _stats(vals) -> Optional[dict]:
             "min": min(vals), "max": max(vals), "n": len(vals)}
 
 
+def _span_order(phases: dict) -> list:
+    """(depth, name) of every span, children under their parent, each
+    level by total time."""
+    kids: dict = {}
+    for name, p in phases.items():
+        parent = p.get("parent")
+        kids.setdefault(parent if parent in phases else None, []).append(name)
+    out = []
+
+    def walk(parent, depth):
+        for name in sorted(kids.get(parent, ()),
+                           key=lambda n: -phases[n]["total_sec"]):
+            out.append((depth, name))
+            walk(name, depth + 1)
+    walk(None, 0)
+    return out
+
+
+def span_table(phases: dict) -> list:
+    """The span tree as text lines: calls, total and self seconds, median
+    and max ms per call (with the step of the max), and the steps whose
+    span compiled.  Logs without self/median/max show the mean."""
+    lines = [f"  {'span':<20}{'calls':>7}{'total s':>10}{'self s':>10}"
+             f"{'median ms':>11}{'max ms':>10}{'at step':>9}  compiled at"]
+    for depth, name in _span_order(phases):
+        p = phases[name]
+        med = p.get("median_ms", p.get("mean_ms"))
+        steps = p.get("compiled_steps") or []
+        mx = p.get("max_ms")
+        lines.append(
+            f"  {'  ' * depth + name:<20}{p['count']:>7}"
+            f"{p['total_sec']:>10.4f}"
+            f"{p.get('self_sec', p['total_sec']):>10.4f}{med:>11.3f}"
+            f"{'-' if mx is None else f'{mx:.3f}':>10}"
+            f"{_fmt(p.get('max_step')):>9}"
+            f"  {', '.join(str(k) for k in steps) or '-'}")
+    return lines
+
+
 def render(events: list, width: int = 32) -> str:
     """The full text report for one event log."""
     meta = next((e for e in events if e.get("event") == "meta"), {})
@@ -81,12 +121,7 @@ def render(events: list, width: int = 32) -> str:
     if phases:
         lines.append("")
         lines.append("-- phases " + "-" * (width + 18))
-        lines.append(f"  {'phase':<12}{'total s':>10}{'calls':>8}"
-                     f"{'mean ms':>10}")
-        for name, p in sorted(phases.items(),
-                              key=lambda kv: -kv[1]["total_sec"]):
-            lines.append(f"  {name:<12}{p['total_sec']:>10.4f}"
-                         f"{p['count']:>8}{p['mean_ms']:>10.3f}")
+        lines.extend(span_table(phases))
 
     metric_keys = ["loss", *OBS_METRICS]
     shown = [k for k in metric_keys if _series(steps, k)]

@@ -25,6 +25,7 @@ from repro.obs import (
     theoretical_floor,
 )
 from repro.obs import metrics as obs_metrics, optimality, report
+from repro.obs import trace as obs_trace
 from repro.sim.telemetry import TelemetryRecorder
 
 N, D = 4, 6
@@ -237,26 +238,33 @@ def test_resolve_names():
 
 def test_tracer_spans_and_drain():
     tr = Tracer()
-    with tr.span("step"):
+    with tr.span("dispatch"):
         pass
-    with tr.span("step"):
+    with tr.span("dispatch"):
         pass
     with tr.span("data"):
         pass
     pending = tr.drain()
-    assert set(pending) == {"step", "data"}
+    assert set(pending) == {"dispatch", "data"}
     assert tr.drain() == {}  # drained
     s = tr.summary()
-    assert s["step"]["count"] == 2 and s["data"]["count"] == 1
-    assert s["step"]["total_sec"] >= 0
+    assert s["dispatch"]["count"] == 2 and s["data"]["count"] == 1
+    assert s["dispatch"]["total_sec"] >= 0
+    assert s["dispatch"]["parent"] is None
 
 
 def test_profiler_writes_trace(tmp_path):
     prof = Profiler(str(tmp_path / "trace"), steps=2)
-    prof.start()
-    assert not prof.maybe_stop(0)
-    assert prof.maybe_stop(1)  # stops at the Nth recorded step
+    prof.step_done(0, compiles=3)  # a compiling step opens nothing
+    assert prof.first is None
+    prof.step_done(1, compiles=0)  # opens after the first clean step
+    assert prof.first == 2
+    prof.step_done(2, compiles=0)
+    prof.step_done(3, compiles=0)  # stops after `steps` traced steps
+    assert (prof.first, prof.last) == (2, 3)
+    prof.step_done(4, compiles=0)  # never reopens
     prof.close()  # idempotent
+    assert (prof.first, prof.last) == (2, 3)
     assert os.path.isdir(str(tmp_path / "trace"))
 
 
@@ -305,8 +313,12 @@ def test_report_renders(tmp_path):
     rec = ObsRecorder(sink, every=3, tracer=tr, gap=gap,
                       meta={"name": "demo", "algo": "dsgd"})
     for k in range(7):
-        with tr.span("step"):
-            pass
+        with tr.step(k):
+            with tr.span("dispatch"):
+                pass
+            with tr.span("record"):
+                with tr.span("record.sync"):
+                    pass
         rec.record(k, (k + 1) * 2, None,
                    {"loss": jnp.float32(1.0 / (k + 1)),
                     "obs": {"grad_norm": jnp.float32(2.0 / (k + 1))}}, 0.01)
@@ -317,6 +329,12 @@ def test_report_renders(tmp_path):
     assert "grad_norm" in text and "loss" in text
     assert "optimality gap" in text and "gap ratio" in text
     assert "phases" in text
+    # the span tree: the child indented under its parent
+    rows = [line.split() for line in text.splitlines()]
+    names = [r[0] for r in rows if r and r[0] in
+             ("dispatch", "record", "record.sync")]
+    assert set(names) == {"dispatch", "record", "record.sync"}
+    assert "    record.sync" in text and "  record " in text
     assert any(c in text for c in "▁▂▃▄▅▆▇█")
     # the CLI path end to end on a real file
     path = str(tmp_path / "log.jsonl")
@@ -399,7 +417,10 @@ def test_exp_run_obs_end_to_end(tmp_path):
         assert name in stepev, name
     summ = events[-1]
     assert summ["optimality"]["gap_ratio"] is not None
-    assert {"data", "step", "telemetry"} <= set(summ["phases"])
+    assert {"data", "dispatch", "record"} <= set(summ["phases"])
+    # step 0 compiled its step program inside the dispatch span
+    assert stepev["step"] == 0 and stepev["compiles"] > 0
+    assert 0 in summ["phases"]["dispatch"]["compiled_steps"]
     # manifest written next to the event log, records the log + obs names
     m = exp.load_manifest(exp.manifest_path(log))
     assert m["spec_parsed"] == sp
@@ -431,3 +452,257 @@ def test_engine_obs_unknown_name_raises():
     Ws = jnp.asarray(_sched().stacked(0, 1))
     with pytest.raises(ValueError, match="unknown obs metric"):
         algo.step(state, _host_grad, Ws, KEY, obs=("bogus",))
+
+
+# ---------------------------------------------------------------------------
+# The loop's spans, the compile counter and the profiler window
+# ---------------------------------------------------------------------------
+
+ARCH_STEPS = 4
+RECORD_CHILDREN = ("record.telemetry", "record.sync", "record.readback")
+
+
+def _arch_spec(tmp, **obs):
+    from repro import exp
+
+    return exp.ExperimentSpec(
+        model=exp.ModelRef(kind="arch", arch="qwen1.5-0.5b",
+                           preset="reduced"),
+        data=exp.DataSpec(batch=1, seq=16),
+        algorithm=exp.AlgorithmSpec(name="mc_dsgt", R=1),
+        topology=exp.TopologySpec(kind="one-peer-exp"),
+        run=exp.RunSpec(steps=ARCH_STEPS, nodes=2, gossip_impl="auto",
+                        telemetry=str(tmp / "telemetry.json"),
+                        checkpoint=str(tmp / "ck.msgpack")),
+        obs=exp.ObsSpec(**obs))
+
+
+def _spans_since(t0):
+    return [s for s in obs_trace.spans() if s.start >= t0]
+
+
+@pytest.fixture(scope="module")
+def arch_run(tmp_path_factory):
+    """exp.run of the arch runtime with the default ObsSpec (a telemetry
+    recorder and a checkpoint, so every span has work), and its spans."""
+    import time
+
+    from repro import exp
+
+    t0 = time.perf_counter()
+    res = exp.run(_arch_spec(tmp_path_factory.mktemp("arch")), quiet=True)
+    return res, _spans_since(t0)
+
+
+def test_exp_run_records_every_span_every_step(arch_run):
+    res, got = arch_run
+    assert not res.spec.obs.enabled
+    for k in range(ARCH_STEPS):
+        names = sorted(s.name for s in got if s.k == k)
+        assert names == sorted(("data", "dispatch", "record")
+                               + RECORD_CHILDREN), k
+    ck = [s for s in got if s.name == "checkpoint"]
+    assert len(ck) == 1 and ck[0].k is None and ck[0].parent is None
+    assert set(obs_trace.SPANS) == {s.name for s in got}
+
+
+def test_spans_carry_step_and_parent(arch_run):
+    _, got = arch_run
+    by = {(s.name, s.k): s for s in got}
+    for k in range(ARCH_STEPS):
+        for name in ("data", "dispatch", "record"):
+            assert by[name, k].parent is None
+        rec = by["record", k]
+        assert by["dispatch", k].end <= rec.start
+        for name in RECORD_CHILDREN:
+            child = by[name, k]
+            assert child.parent == "record"
+            assert rec.start <= child.start <= child.end <= rec.end
+        # the children run in order: telemetry, sync, readback
+        assert by["record.telemetry", k].end <= by["record.sync", k].start
+        assert by["record.sync", k].end <= by["record.readback", k].start
+    summ = arch_run[0].built.tracer.summary()
+    assert summ["record.sync"]["parent"] == "record"
+    rec = summ["record"]
+    assert rec["self_sec"] == pytest.approx(
+        rec["total_sec"] - sum(summ[n]["total_sec"]
+                               for n in RECORD_CHILDREN), abs=1e-9)
+
+
+def test_history_ready_is_the_sync_spans_end(arch_run):
+    res, got = arch_run
+    sync = {s.k: s.end for s in got if s.name == "record.sync"}
+    assert [row["ready"] for row in res.history] == \
+        [sync[k] for k in range(ARCH_STEPS)]
+
+
+def test_first_step_compiles_in_its_dispatch_span(arch_run):
+    _, got = arch_run
+    dispatch = {s.k: s.compiles for s in got if s.name == "dispatch"}
+    assert dispatch[0] >= 1
+    assert all(dispatch[k] == 0 for k in range(1, ARCH_STEPS))
+
+
+def test_span_ring_is_bounded():
+    tr = Tracer()
+    for i in range(obs_trace.RING_SIZE + 5):
+        with tr.span("ring"):
+            pass
+    got = obs_trace.spans()
+    assert len(got) == obs_trace.RING_SIZE
+    assert all(s.name == "ring" for s in got)
+    assert tr.summary()["ring"]["count"] == obs_trace.RING_SIZE + 5
+
+
+def test_summary_statistics_cover_every_call(monkeypatch):
+    # 5,000 long calls, then 4,096 short ones: the median of every call
+    # is the long one (a window of the last 4,096 would read the short);
+    # binary fractions keep the fake clock exact
+    long, short = 2.0 ** -7, 2.0 ** -10
+    clock = [0.0]
+    monkeypatch.setattr(obs_trace.time, "perf_counter", lambda: clock[0])
+    tr = Tracer()
+    for i, dur in enumerate([long] * 5000 + [short] * 4096):
+        tr.k = i
+        with tr.span("s"):
+            clock[0] += dur
+    s = tr.summary()["s"]
+    assert s["count"] == 9096
+    assert s["median_ms"] == 1e3 * long
+    assert s["total_sec"] == 5000 * long + 4096 * short
+    assert s["mean_ms"] == pytest.approx(1e3 * s["total_sec"] / 9096)
+    assert (s["max_ms"], s["max_step"]) == (1e3 * long, 0)
+
+
+def test_module_span_joins_the_open_spans_tracer():
+    tr = Tracer()
+    tr.k = 7
+    with tr.span("record"):
+        with obs_trace.span("record.telemetry") as child:
+            pass
+    assert (child.k, child.parent_name) == (7, "record")
+    assert tr.summary()["record.telemetry"]["parent"] == "record"
+    with pytest.raises(RuntimeError, match="outside any span"):
+        obs_trace.span("record.telemetry")
+
+
+def test_run_algorithm_spans_its_telemetry_under_record():
+    import time
+
+    sched = _sched()
+    t0 = time.perf_counter()
+    driver.run_algorithm(alg.dsgd(0.2), jnp.zeros((N, D)), _host_grad,
+                         sched, 3, KEY,
+                         telemetry=TelemetryRecorder(sched, wps=1, window=4))
+    got = _spans_since(t0)
+    for k in range(3):
+        names = {s.name: s.parent for s in got if s.k == k}
+        assert names == {"data": None, "dispatch": None, "record": None,
+                         "record.telemetry": "record"}, k
+
+
+def test_forced_recompile_is_credited_to_its_steps_dispatch():
+    import time
+
+    step = driver.bind_step(
+        driver.StagedGossip("auto", None, 1, 1),
+        lambda state, extra, tensors, t: (state + jnp.sum(extra), None))
+    # a new input shape at step 3: one more compile, in that dispatch
+    extra = lambda k: jnp.ones((4 + (k >= 3),), jnp.float32)  # noqa: E731
+    c0 = obs_trace.compile_counts()["compiles"]
+    t0 = time.perf_counter()
+    tr = Tracer()
+    driver.run_loop(step, jnp.float32(0), steps=5, wps=1, period=1,
+                    extra_fn=extra, record=lambda *a: None, tracer=tr)
+    dispatch = {s.k: s.compiles for s in _spans_since(t0)
+                if s.name == "dispatch"}
+    assert dispatch[0] >= 1 and dispatch[3] >= 1
+    assert dispatch[1] == dispatch[2] == dispatch[4] == 0
+    assert tr.summary()["dispatch"]["compiled_steps"] == [0, 3]
+    assert obs_trace.compile_counts()["compiles"] - c0 >= 2
+
+
+def _xplane_events(directory):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                     recursive=True)
+    assert path, f"no trace under {directory}"
+    data = ProfileData.from_file(path[0])
+    return {plane.name: [(line.name, e.name, e.start_ns,
+                          e.start_ns + e.duration_ns)
+                         for line in plane.lines for e in line.events]
+            for plane in data.planes}
+
+
+def test_spans_share_the_profilers_timeline(tmp_path):
+    # the program's spans land on the host plane of a profiler trace, and
+    # each step's execution lies between its dispatch start and its sync
+    # end (sin marks the step's own op on the CPU's XLA threads)
+    tr = Tracer()
+    span = tr.span
+
+    @jax.jit
+    def step(state, x, t):
+        return state + jnp.sum(jnp.sin(x)) * t, {"loss": state}
+
+    def record(k, t, state, out, dt):
+        with span("record.sync"):
+            jax.block_until_ready((state, out))
+        with span("record.readback"):
+            return float(out["loss"])
+
+    x = jnp.ones((64, 64), jnp.float32)
+    driver.run_loop(step, jnp.float32(0), steps=2, wps=1, period=4,
+                    extra_fn=lambda k: x, record=record, tracer=tr)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        driver.run_loop(step, jnp.float32(0), steps=3, wps=1, period=4,
+                        start_step=2, extra_fn=lambda k: x, record=record,
+                        tracer=tr)
+    finally:
+        jax.profiler.stop_trace()
+    planes = _xplane_events(str(tmp_path))
+    host = planes["/host:CPU"]
+    names = {n for _, n, _, _ in host}
+    assert {"repro/data", "repro/dispatch", "repro/record",
+            "repro/record.sync", "repro/record.readback"} <= names
+    assert sum(1 for _, n, _, _ in host if n == "train") == 3
+    dispatch = sorted(s for _, n, s, _ in host if n == "repro/dispatch")
+    sync_end = sorted(e for _, n, _, e in host if n == "repro/record.sync")
+    runs = sorted(s for line, n, s, _ in host
+                  if n == "wrapped_sine" and line.startswith("tf_XLA"))
+    assert len(dispatch) == len(sync_end) == len(runs) == 3
+    for a, r, b in zip(dispatch, runs, sync_end):
+        assert a <= r <= b
+
+
+def test_profiler_traces_steady_steps_only(tmp_path):
+    # the first step compiles; the trace opens after the first step that
+    # compiled nothing and holds the `profile_steps` steps after it
+    import time
+
+    from repro import exp
+
+    sp = exp.from_dict({
+        "model": {"kind": "logreg", "d": 8, "m": 32},
+        "algorithm": {"name": "dsgd"},
+        "run": {"steps": 7, "nodes": 4},
+        "obs": {"profile_dir": str(tmp_path / "prof"), "profile_steps": 2},
+    })
+    t0 = time.perf_counter()
+    res = exp.run(sp)
+    got = _spans_since(t0)
+    compiled = {}
+    for s in got:
+        compiled[s.k] = compiled.get(s.k, 0) + s.compiles
+    assert compiled[0] > 0
+    clean = min(k for k, n in compiled.items() if k is not None and n == 0)
+    prof = res.built.obs.profiler
+    assert (prof.first, prof.last) == (clean + 1, clean + 2)
+    assert all(compiled[k] == 0 for k in (prof.first, prof.last))
+    steps = [n for _, n, _, _ in _xplane_events(str(tmp_path / "prof"))
+             ["/host:CPU"] if n == "train"]
+    assert len(steps) == 2
