@@ -491,9 +491,14 @@ def _run_arch(built: Built, *, quiet: bool = False) -> Result:
         with span("record.sync") as sync:
             jax.block_until_ready((state, out))
         with span("record.readback"):
-            loss = float(out["loss"])
-            ce = (tl["consensus"] if tl is not None
-                  else sim_telemetry.consensus_distance(state.x))
+            if tl is not None:
+                loss, ce = float(out["loss"]), tl["consensus"]
+            else:
+                # one device program and one transfer for both numbers
+                loss, sums = jax.device_get(
+                    (out["loss"], sim_telemetry.consensus_sums(state.x)))
+                loss, ce = (float(loss),
+                            sim_telemetry.consensus_from_sums(sums))
             extra = ""
             if tl is not None:
                 ed = tl["eff_diameter"]

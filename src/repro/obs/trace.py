@@ -12,8 +12,8 @@ one span per host phase; the ``arch`` record hook
   ``record.telemetry`` (the chained ObsRecorder / TelemetryRecorder),
   ``record.sync`` (``jax.block_until_ready`` of the step's output; its
   end is the history row's ``ready`` stamp) and ``record.readback``
-  (from the sync to the hook's return: loss, consensus distance, the
-  console line);
+  (from the sync to the hook's return: the consensus sums' program, one
+  transfer of them and the loss, the console line);
 * ``checkpoint`` -- ``save_fn``.
 
 A span (:class:`Span`) records its name, the step ``k``, its parent's

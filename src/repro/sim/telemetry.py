@@ -69,16 +69,30 @@ TELEMETRY_FIELDS = {
 }
 
 
+@jax.jit
+def consensus_sums(x: Any) -> jax.Array:
+    """Per-leaf sums of (x - x̄)² of a stacked pytree (node axis 0), in
+    leaf order, as one device vector: one program over the whole tree,
+    so the subtract, square and sum fuse and only each leaf's mean is
+    written to memory.  Each sum is taken in its leaf's own dtype; the
+    vector has the widest of them, which holds each sum exactly."""
+    return jnp.stack([
+        jnp.sum((leaf - jnp.mean(leaf, axis=0, keepdims=True)) ** 2)
+        for leaf in jax.tree.leaves(x)])
+
+
+def consensus_from_sums(sums: Any) -> float:
+    """||x - x̄||_F from :func:`consensus_sums`' vector once it is on the
+    host: the per-leaf sums added in Python float in leaf order."""
+    return sum(np.asarray(sums).tolist()) ** 0.5
+
+
 def consensus_distance(x: Any) -> float:
     """||x - x̄||_F over every leaf of a stacked pytree (node axis 0).
-    Reduces on device — only one scalar per leaf crosses the host
-    boundary, so it is safe to call on full model states."""
-    tot = 0.0
-    for leaf in jax.tree.leaves(x):
-        arr = jnp.asarray(leaf)
-        xb = jnp.mean(arr, axis=0, keepdims=True)
-        tot += float(jnp.sum((arr - xb) ** 2))
-    return tot ** 0.5
+    Reduces on device in one program (:func:`consensus_sums`) and crosses
+    the host boundary once, with one small vector, so it is safe to call
+    on full model states."""
+    return consensus_from_sums(jax.device_get(consensus_sums(x)))
 
 
 def windowed_spectral_gap(mats: np.ndarray) -> float:

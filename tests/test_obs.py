@@ -543,6 +543,53 @@ def test_first_step_compiles_in_its_dispatch_span(arch_run):
     assert all(dispatch[k] == 0 for k in range(1, ARCH_STEPS))
 
 
+@pytest.fixture(scope="module")
+def bare_arch_run(tmp_path_factory):
+    """exp.run of the arch runtime with no recorder, as the chip cells run
+    it, so the record hook reads back the loss and the consensus distance
+    itself; with every step's ``float(out["loss"])`` taken after its hook
+    and JAX's caches cleared first, so step 0 compiles what it uses."""
+    import dataclasses
+    import time
+
+    from repro import exp
+
+    spec = _arch_spec(tmp_path_factory.mktemp("bare"))
+    spec = dataclasses.replace(spec, run=dataclasses.replace(
+        spec.run, telemetry=None, checkpoint=None))
+    run_loop, losses = driver.run_loop, []
+
+    def spy_loop(*args, record, **kw):
+        def spy(k, t, state, out, dt):
+            row = record(k, t, state, out, dt)
+            losses.append(float(out["loss"]))
+            return row
+        return run_loop(*args, record=spy, **kw)
+
+    jax.clear_caches()
+    t0 = time.perf_counter()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(driver, "run_loop", spy_loop)
+        res = exp.run(spec, quiet=True)
+    return res, losses, _spans_since(t0)
+
+
+def test_record_hook_reads_back_in_one_program(bare_arch_run):
+    from test_sim import eager_consensus_distance
+
+    res, losses, got = bare_arch_run
+    assert res.telemetry is None
+    assert [row["loss"] for row in res.history] == losses
+    assert res.history[-1]["consensus"] == pytest.approx(
+        eager_consensus_distance(res.state.x), rel=1e-5)
+    assert all(set(row) == {"step", "loss", "consensus", "sec", "ready"}
+               for row in res.history)
+    # the fused reduction is the readback's one new program
+    readback = {s.k: s.compiles for s in got if s.name == "record.readback"}
+    assert 1 <= readback[0] <= 2
+    assert all(readback[k] == 0 for k in range(1, ARCH_STEPS))
+
+
 def test_span_ring_is_bounded():
     tr = Tracer()
     for i in range(obs_trace.RING_SIZE + 5):

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from repro.core import algorithms as alg, driver, gossip, topology as topo
+from repro.sim import telemetry as sim_telemetry
 from repro.sim import (BernoulliDropChannel, GilbertElliottChannel,
                        LinkLatencyModel, NodeChurn, StragglerInjection,
                        TelemetryRecorder, combined_mask,
@@ -255,6 +256,46 @@ def test_consensus_distance_zero_iff_consensus():
     assert consensus_distance({"w": x}) == 0.0
     x2 = x.at[0].set(2.0)
     assert consensus_distance({"w": x2}) > 0.5
+
+
+def eager_consensus_distance(x):
+    """The per-leaf eager formula, one host sync per leaf: the oracle of
+    the fused ``consensus_distance``."""
+    tot = 0.0
+    for leaf in jax.tree.leaves(x):
+        arr = jnp.asarray(leaf)
+        xb = jnp.mean(arr, axis=0, keepdims=True)
+        tot += float(jnp.sum((arr - xb) ** 2))
+    return tot ** 0.5
+
+
+def _ranked_tree(n, dtype, seed=0):
+    """Leaves of rank 1 to 4, off-centre so the mean matters."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    shapes = {"s": (n,), "b": (n, 40), "w": (n, 16, 24), "k": (n, 3, 4, 5)}
+    return {name: (3.0 + jax.random.normal(k, shp)).astype(dtype)
+            for k, (name, shp) in zip(ks, shapes.items())}
+
+
+@pytest.mark.parametrize("n", [2, 8])
+@pytest.mark.parametrize("dtype,rtol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 2.0 ** -8)])
+def test_consensus_distance_matches_eager_formula(n, dtype, rtol):
+    # bf16: within one bf16 epsilon (the fused program may keep the
+    # square's excess precision inside the fusion)
+    x = _ranked_tree(n, dtype)
+    want = eager_consensus_distance(x)
+    assert want > 0
+    assert consensus_distance(x) == pytest.approx(want, rel=rtol)
+    assert sim_telemetry.consensus_sums(x).shape == (len(x),)
+
+
+def test_consensus_distance_mixed_dtypes():
+    x = {"a": _ranked_tree(4, jnp.float32)["w"],
+         "b": _ranked_tree(4, jnp.bfloat16, seed=1)["b"]}
+    assert sim_telemetry.consensus_sums(x).dtype == jnp.float32
+    assert consensus_distance(x) == pytest.approx(
+        eager_consensus_distance(x), rel=2.0 ** -8)
 
 
 def test_windowed_spectral_gap_and_diameter():
