@@ -43,12 +43,11 @@ def _seeds(text):
 
 
 def calibrate(bench, workload, *, seeds, control_seeds, fault_seeds,
-              faults_to_plant, preset="full", log=print):
+              faults_to_plant, preset="full", root=HERE, log=print):
     import jax.numpy as jnp
 
-    entry = harness.bench_entry(bench, workload)
-    cfg = harness.load_json(HERE, "configs", entry["config"] + ".json")
-    traffic = harness.load_json(HERE, "traffic", entry["traffic"] + ".json")
+    cell = harness.find_cell(bench, workload, root)
+    cfg, traffic = cell.cfg, cell.traffic
     refs = {}
 
     def program(seed, fault=None):
@@ -56,7 +55,7 @@ def calibrate(bench, workload, *, seeds, control_seeds, fault_seeds,
         spec = harness.make_spec(cfg, traffic, seed, preset)
         with faults.planted(fault) if fault else contextlib.nullcontext():
             built, shapes = harness.drive(
-                spec, cfg, traffic, plan, preset=preset,
+                spec, cfg, traffic, plan, preset=preset, rule=cell.rule,
                 model_wrap=faults.model_wrap(fault) if fault else None)
         gc.collect()
         return plan.got, built, shapes
@@ -65,7 +64,7 @@ def calibrate(bench, workload, *, seeds, control_seeds, fault_seeds,
         key = (seed, bool(low))
         if key not in refs:
             refs[key] = harness.reference_readings(
-                entry["config"], cfg, traffic, built.cfg, shapes, seed, **low)
+                cell, built.cfg, shapes, seed, preset=preset, **low)
         return refs[key]
 
     rows = []

@@ -9,6 +9,14 @@ import jax
 import jax.numpy as jnp
 
 
+def sizes(c):
+    """The file's size keys as a program config ``c`` holds them."""
+    return {"hidden_size": c.d_model, "intermediate_size": c.d_ff,
+            "num_attention_heads": c.num_heads,
+            "num_key_value_heads": c.num_kv_heads,
+            "num_hidden_layers": c.num_layers, "vocab_size": c.vocab_size}
+
+
 def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 

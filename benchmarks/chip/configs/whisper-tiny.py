@@ -14,6 +14,16 @@ import jax.numpy as jnp
 EPS = 1e-6
 
 
+def sizes(c):
+    """The file's size keys as a program config ``c`` holds them."""
+    return {"d_model": c.d_model, "encoder_layers": c.encoder_layers,
+            "decoder_layers": c.num_layers,
+            "encoder_attention_heads": c.num_heads,
+            "decoder_attention_heads": c.num_heads,
+            "encoder_ffn_dim": c.d_ff, "decoder_ffn_dim": c.d_ff,
+            "vocab_size": c.vocab_size, "max_source_positions": c.encoder_seq}
+
+
 def _ln(x, p):
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean((x - mu) ** 2, -1, keepdims=True)

@@ -18,6 +18,12 @@ functions for the length of a run:
   loop's data, step and record calls are timed on the host, to name the
   phase of a step that stalls.
 
+A cell's files are found by name under a root (``benchmarks/chip/`` by
+default): ``configs/<config>.json`` and its module ``configs/<config>.py``,
+``traffic/<traffic>.json`` and ``limits/<workload>.json``. The module holds
+the plain reference's ``loss(params, batch, cfg)`` and may state what the
+harness needs to know of its model (see ``Cell``).
+
 ``run_cell`` returns the result object the command prints.
 """
 
@@ -35,6 +41,7 @@ import statistics
 import sys
 import tempfile
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +80,60 @@ def bench_entry(bench: dict, workload: str) -> dict:
             return w
     raise KeyError(f"no workload {workload!r} in BENCHMARK.json "
                    f"(have {[w['name'] for w in bench['workloads']]})")
+
+
+@dataclasses.dataclass
+class Cell:
+    """One workload's files, found by name under ``root``.
+
+    ``model`` is the configuration's module ``configs/<config>.py``. Its
+    ``loss(params, batch, cfg)`` is the plain reference; besides, it may
+    state:
+
+    * ``WEIGHTS``: how its leaves are drawn, before the built-in rule
+      (``inputs.WeightRule``);
+    * ``forward_flops(cfg, tokens)``: forward matmul FLOPs of one sequence
+      (``counting.step_flops``; the built-in count otherwise);
+    * ``sizes(program_cfg)``: the file's size keys as a program config
+      holds them, read at a preset other than ``full`` (CPU tests), where
+      the program runs smaller than the file states.
+    """
+
+    name: str
+    cfg: dict
+    model: types.ModuleType
+    traffic: dict
+    root: str
+
+    @property
+    def limits(self) -> dict:
+        return load_json(self.root, "limits", self.name + ".json")
+
+    @property
+    def rule(self):
+        return inputs.WeightRule(getattr(self.model, "WEIGHTS", None),
+                                 origin=self.model.__file__)
+
+    def cfg_at(self, program_cfg, preset: str) -> dict:
+        """The configuration as the run builds it: the file's, or at a
+        smaller preset, with the sizes the program holds there."""
+        if preset == "full" or not hasattr(self.model, "sizes"):
+            return self.cfg
+        return {**self.cfg, **self.model.sizes(program_cfg)}
+
+
+def config_module(root: str, config: str) -> types.ModuleType:
+    return load_module(os.path.join(root, "configs", config + ".py"),
+                       "config_" + config.replace(".", "_").replace("-", "_"))
+
+
+def find_cell(bench: dict, workload: str, root: str = HERE) -> Cell:
+    entry = bench_entry(bench, workload)
+    return Cell(name=workload,
+                cfg=load_json(root, "configs", entry["config"] + ".json"),
+                model=config_module(root, entry["config"]),
+                traffic=load_json(root, "traffic", entry["traffic"] + ".json"),
+                root=root)
 
 
 def peaks_for(kind: str) -> dict:
@@ -132,14 +193,15 @@ def spread_norms(tree) -> np.ndarray:
     return np.asarray(f(tree), np.float64)
 
 
-def change_norms(x, shapes, seed) -> np.ndarray:
+def change_norms(x, shapes, seed, rule) -> np.ndarray:
     """(leaves, n) norms of x - x0 per node: x node-stacked, x0 the seed's
     weights, made inside the same program so that no copy of them is kept
     beside the state."""
     f = jax.jit(lambda a, key: jnp.stack([
         jnp.sqrt(jnp.sum(jnp.square(u.astype(jnp.float32) - v[None]).reshape(
             u.shape[0], -1), axis=1))
-        for u, v in zip(jax.tree.leaves(a), inputs.weight_leaves(shapes, key))]))
+        for u, v in zip(jax.tree.leaves(a),
+                        inputs.weight_leaves(shapes, key, rule))]))
     return np.asarray(f(x, inputs.weights_key(seed)), np.float64)
 
 
@@ -191,10 +253,12 @@ def patched(obj, name, value):
         setattr(obj, name, old)
 
 
-def drive(spec, cfg, traffic, plan: Plan, *, preset: str, model_wrap=None):
-    """``exp.run(spec)`` with the benchmark's weights, the configuration's
-    overrides and the loop cut into segments; returns the run's ``Built``
-    and the weights' shapes."""
+def drive(spec, cfg, traffic, plan: Plan, *, preset: str, rule,
+          model_wrap=None):
+    """``exp.run(spec)`` with the benchmark's weights, drawn by ``rule``,
+    the configuration's overrides and the loop cut into segments; returns
+    the run's ``Built`` and the weights' shapes. A leaf that ``rule`` does
+    not place stops the run before its first compile."""
     from repro import exp
     from repro.core import driver
     from repro.models import build as build_model
@@ -213,8 +277,10 @@ def drive(spec, cfg, traffic, plan: Plan, *, preset: str, model_wrap=None):
             check_program_config(cfg, built.cfg)
         shapes = jax.eval_shape(lambda k: built.model.init(k, jnp.float32),
                                 jax.random.key(0))
+        rule.check(shapes)
         model = built.model._replace(
-            init=lambda key, dtype=None: inputs.make_weights(shapes, seed))
+            init=lambda key, dtype=None: inputs.make_weights(shapes, seed,
+                                                             rule))
         built.model = model_wrap(model) if model_wrap else model
         box.update(built=built, shapes=shapes)
         plan.info["built"] = time.perf_counter()
@@ -256,7 +322,7 @@ def drive(spec, cfg, traffic, plan: Plan, *, preset: str, model_wrap=None):
         if plan.spread:
             plan.got["spread"] = spread_norms(state.h)
         seg(reference.STEPS - 1)
-        plan.got["change"] = change_norms(state.x, box["shapes"], seed)
+        plan.got["change"] = change_norms(state.x, box["shapes"], seed, rule)
         plan.got["loss"] = [r["loss"] for r in hist[:reference.STEPS]]
         plan.info["first_ready"] = hist[0]["ready"]
         plan.info["checked"] = time.perf_counter()
@@ -337,15 +403,15 @@ def weights_fn(traffic, seed):
     return at
 
 
-def reference_readings(cfg_name, cfg, traffic, program_cfg, shapes, seed, *,
+def reference_readings(cell: Cell, program_cfg, shapes, seed, *, preset,
                        dtype=jnp.float32, precision="highest"):
-    ref_mod = load_module(os.path.join(HERE, "configs", cfg_name + ".py"),
-                          "ref_" + cfg_name.replace(".", "_").replace("-", "_"))
+    traffic = cell.traffic
     stream = stream_for(program_cfg, traffic, seed)
-    x0 = inputs.make_weights(shapes, seed)
-    return reference.run(ref_mod.loss, cfg, x0, stream.batch_at,
-                         weights_fn(traffic, seed), nodes=traffic["nodes"],
-                         R=traffic["R"], gamma=traffic["gamma"], dtype=dtype,
+    x0 = inputs.make_weights(shapes, seed, cell.rule)
+    return reference.run(cell.model.loss, cell.cfg_at(program_cfg, preset), x0,
+                         stream.batch_at, weights_fn(traffic, seed),
+                         nodes=traffic["nodes"], R=traffic["R"],
+                         gamma=traffic["gamma"], dtype=dtype,
                          precision=precision)
 
 
@@ -369,28 +435,28 @@ def metric_applies(m: dict, workload: str) -> bool:
 
 def run_cell(bench: dict, workload: str, *, seed: int, seconds: float,
              trace: bool, t0: float, preset: str = "full",
-             peaks: dict | None = None, model_wrap=None,
+             peaks: dict | None = None, model_wrap=None, root: str = HERE,
              log=lambda obj: print(json.dumps(obj), flush=True)) -> dict:
     """One run: set-up, window (or traced stretch), reference, comparison.
-    Returns the result object; earlier lines go through ``log``."""
-    entry = bench_entry(bench, workload)
-    cfg = load_json(HERE, "configs", entry["config"] + ".json")
-    traffic = load_json(HERE, "traffic", entry["traffic"] + ".json")
-    limits = load_json(HERE, "limits", workload + ".json")
+    The cell's files are found under ``root``. Returns the result object;
+    earlier lines go through ``log``."""
+    cell = find_cell(bench, workload, root)
+    traffic, limits = cell.traffic, cell.limits
     Compiles.watch()
     plan = Plan(seconds=seconds, trace=trace, check_only=False, t0=t0,
                 spread="spread_gap" in limits)
-    spec = make_spec(cfg, traffic, seed, preset)
-    built, shapes = drive(spec, cfg, traffic, plan, preset=preset,
-                          model_wrap=model_wrap)
+    spec = make_spec(cell.cfg, traffic, seed, preset)
+    built, shapes = drive(spec, cell.cfg, traffic, plan, preset=preset,
+                          rule=cell.rule, model_wrap=model_wrap)
     dev = device_info()
     window = plan.info["window"]
     ready = [r["ready"] for r in window]
     losses = [r["loss"] for r in window[1:]]
+    cfg = cell.cfg_at(built.cfg, preset)
     facts = {"cfg": cfg, "traffic": traffic, "peaks": peaks,
              "setup_s": plan.info["window_start"] - t0, "ready": ready,
              "positions_per_step": counting.positions_per_step(cfg, traffic),
-             "flops_per_step": counting.step_flops(cfg, traffic),
+             "flops_per_step": counting.step_flops(cfg, traffic, cell.model),
              "memory_peak_bytes": dev["memory_peak_bytes"], "trace": None,
              "state_entries": sum(math.prod(s.shape) for s in
                                   jax.tree.leaves(shapes))}
@@ -423,8 +489,7 @@ def run_cell(bench: dict, workload: str, *, seed: int, seconds: float,
 
     gc.collect()
     r0 = time.perf_counter()
-    ref = reference_readings(entry["config"], cfg, traffic, built.cfg, shapes,
-                             seed)
+    ref = reference_readings(cell, built.cfg, shapes, seed, preset=preset)
     log({"phase": "reference", "seconds": time.perf_counter() - r0})
     read = reference.readings(plan.got, ref)
     checks = {k: {"value": read[k], "limit": limits[k]} for k in limits}

@@ -2,11 +2,18 @@
 
 Model FLOPs are checked against XLA's own count of the reduced-preset
 training step with every scan unrolled (a rolled scan's body is counted
-once). XLA also counts the elementwise work (norms, softmax, activations,
-the update, the clip), which the model count leaves out, so the model
-count must lie a little under XLA's: between 85% and 100% of it."""
+once), for every configuration the harness can find, at the sizes its
+module's ``sizes`` reads off the program's config. XLA also counts the
+elementwise work (norms, softmax, activations, the update, the clip),
+which the model count leaves out, so the model count must lie a little
+under XLA's: between 85% and 100% of it. The step is compiled at expert
+capacity factor 1, where each expert's buffer holds its mean share of the
+tokens: XLA then counts the routed work without the capacity padding
+that the count leaves out (but with the one-hot dispatch and combine
+products, which it counts and the model count does not)."""
 
 import dataclasses
+import glob
 import os
 import sys
 
@@ -22,33 +29,34 @@ import counting  # noqa: E402
 import harness  # noqa: E402
 
 
-def _config_json(cfg) -> dict:
-    """The reduced program config in the configuration files' keys."""
-    if cfg.arch_type == "audio":
-        return {"d_model": cfg.d_model, "encoder_layers": cfg.encoder_layers,
-                "decoder_layers": cfg.num_layers,
-                "encoder_attention_heads": cfg.num_heads,
-                "decoder_attention_heads": cfg.num_heads,
-                "encoder_ffn_dim": cfg.d_ff, "decoder_ffn_dim": cfg.d_ff,
-                "vocab_size": cfg.vocab_size,
-                "max_source_positions": cfg.encoder_seq}
-    return {"hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
-            "num_attention_heads": cfg.num_heads,
-            "num_key_value_heads": cfg.num_kv_heads,
-            "num_hidden_layers": cfg.num_layers,
-            "vocab_size": cfg.vocab_size, "hidden_act": "silu"}
+# (nodes, R, seq) a configuration is counted at; others at (2, 1, 32)
+SHAPES = {"qwen1.5-0.5b": (2, 1, 128), "whisper-tiny": (4, 2, 48)}
 
 
-@pytest.mark.parametrize("arch,nodes,R,seq", [
-    ("qwen1.5-0.5b", 2, 1, 128),
-    ("whisper-tiny", 4, 2, 48),
-])
-def test_model_flops_against_xla_unrolled(arch, nodes, R, seq):
+def _configurations():
+    """Every configuration the harness can find: the benchmark's and the
+    test data's, each as (root, name, nodes, R, seq)."""
+    out = []
+    for root in (HERE, os.path.join(HERE, "testdata")):
+        for path in sorted(glob.glob(os.path.join(root, "configs", "*.json"))):
+            name = os.path.basename(path)[:-len(".json")]
+            shape = SHAPES.get(name, (2, 1, 32))
+            out.append(pytest.param(root, name, *shape,
+                                    id="-".join(map(str, (name, *shape)))))
+    return out
+
+
+@pytest.mark.parametrize("root,config,nodes,R,seq", _configurations())
+def test_model_flops_against_xla_unrolled(root, config, nodes, R, seq):
     from repro import configs
     from repro.dist import steps as dsteps
     from repro.models import build
 
-    cfg = dataclasses.replace(configs.get(arch).reduced(), unroll=True)
+    file_cfg = harness.load_json(root, "configs", config + ".json")
+    module = harness.config_module(root, config)
+    assert hasattr(module, "sizes"), f"configs/{config}.py states no sizes()"
+    cfg = dataclasses.replace(configs.get(file_cfg["arch"]).reduced(),
+                              unroll=True, moe_capacity_factor=1.0)
     init, _, step = dsteps.make_train_step(build(cfg), cfg, algo="mc_dsgt",
                                            gamma=0.05, R=R, unroll=True)
     state = jax.eval_shape(lambda k: init(k, nodes, jnp.float32),
@@ -60,8 +68,8 @@ def test_model_flops_against_xla_unrolled(arch, nodes, R, seq):
     weights = jax.ShapeDtypeStruct((2 * R, nodes, nodes), jnp.float32)
     xla = jax.jit(step).lower(state, batch, weights).compile() \
         .cost_analysis()["flops"]
-    mine = counting.step_flops(_config_json(cfg), {
-        "nodes": nodes, "R": R, "batch": 1, "seq": seq})
+    mine = counting.step_flops({**file_cfg, **module.sizes(cfg)}, {
+        "nodes": nodes, "R": R, "batch": 1, "seq": seq}, module)
     assert 0.85 * xla <= mine <= xla
 
 
@@ -75,6 +83,26 @@ def test_forward_flops_by_hand():
     assert counting.forward_flops(cfg, 3) == 384 + 144 + 576 + 240
     assert counting.step_flops(cfg, {"nodes": 2, "R": 2, "batch": 1,
                                      "seq": 3}) == 3 * 4 * 1344
+
+
+def test_moe_flops_by_hand():
+    # 3 tokens, d=4, experts of width 8 (SwiGLU), 3 experts, 2 a token,
+    # one shared expert: router 2*3*4*3 = 72; each expert a token
+    # 3 * 2*3*4*8 = 576
+    assert counting.moe_mlp(3, 4, 8, experts=3, k=2,
+                            gated=True) == 72 + 2 * 576
+    assert counting.moe_mlp(3, 4, 8, experts=3, k=2, gated=True, shared=1,
+                            shared_f=8) == 72 + 3 * 576
+    # a configuration's module counts where it states forward_flops
+    moe = harness.config_module(os.path.join(HERE, "testdata"),
+                                "granite-moe-3b-a800m")
+    cfg = {"hidden_size": 4, "intermediate_size": 8, "num_attention_heads": 2,
+           "num_key_value_heads": 2, "num_hidden_layers": 1,
+           "num_local_experts": 3, "num_experts_per_tok": 2, "vocab_size": 10}
+    # attention 384 + 144 as in test_forward_flops_by_hand, unembed 240
+    assert counting.forward_flops(cfg, 3, moe) == 528 + 72 + 2 * 576 + 240
+    assert counting.step_flops(cfg, {"nodes": 2, "R": 1, "batch": 1,
+                                     "seq": 3}, moe) == 3 * 2 * 1992
 
 
 def test_positions_per_step():

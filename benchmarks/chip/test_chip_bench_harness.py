@@ -86,11 +86,12 @@ def test_reference_gossip_weights_are_the_realized_schedules(workload):
 
 
 def test_the_configurations_overrides_reach_the_program():
-    cfg = harness.load_json(HERE, "configs", "qwen1.5-0.5b.json")
-    traffic = harness.load_json(HERE, "traffic", "onepeer.n2.r1.b1.s128.json")
+    cell = harness.find_cell(BENCH, "qwen05b.n2.s128")
+    cfg, traffic = cell.cfg, cell.traffic
     plan = harness.Plan(seconds=0, trace=False, check_only=True)
     spec = harness.make_spec(cfg, traffic, SEED, "reduced")
-    built, _ = harness.drive(spec, cfg, traffic, plan, preset="reduced")
+    built, _ = harness.drive(spec, cfg, traffic, plan, preset="reduced",
+                             rule=cell.rule)
     assert cfg["overrides"] == {"rope_theta": 1e6}
     assert built.cfg.rope_theta == 1e6 == cfg["rope_theta"]
 

@@ -1,12 +1,14 @@
-"""The reduction from trace events to per-layer numbers, on a hand-made
-trace with hand-computed answers and on a recorded TPU trace."""
+"""The reduction from trace events to per-layer numbers, on hand-made
+traces with hand-computed answers and on a recorded TPU trace."""
 
 import os
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)), "src"))
 sys.path.insert(0, HERE)
 
+import harness  # noqa: E402
 import trace_reduce  # noqa: E402
 
 
@@ -54,6 +56,43 @@ def test_hand_made_trace():
     # host event over the middle of each
     assert r["idle_gaps"] == [("block_until_ready", 25), ("seg", 25),
                               ("seg", 20), ("record", 10)]
+
+
+def test_every_obs_scope_gets_a_union():
+    # a routing scope nested in obs_grad: a loop's op and its body's ops
+    # nest in time and count once; no op of the window is under obs_mix
+    ev = {
+        "devices": 1,
+        "modules": [_ev("jit_core(1)", 0, 80), _ev("jit_core(1)", 100, 80),
+                    _ev("jit_core(1)", 200, 80)],
+        "ops": [
+            _ev("while.1", 0, 50, "jit(core)/obs_grad/obs_route/while"),
+            _ev("fusion.2", 5, 15,
+                "jit(core)/obs_grad/obs_route/while/body/dot_general"),
+            _ev("fusion.3", 25, 20,
+                "jit(core)/obs_grad/obs_route/while/body/dot_general"),
+            _ev("fusion.4", 50, 20, "jit(core)/obs_grad/dot_general"),
+            _ev("fusion.5", 100, 30, "jit(core)/obs_grad/obs_route/top_k"),
+            _ev("fusion.6", 130, 10, "jit(core)/jobs_queue/add"),
+            _ev("fusion.7", 150, 10, "jit(core)/add"),
+        ],
+        "host": [_ev("seg", 0, 300)],
+    }
+    r = trace_reduce.reduce(ev)
+    # obs_route: [0,50) (the loop, its body inside) + [100,130);
+    # obs_grad: [0,70) + [100,130); obs_mix: none
+    assert r["scope_ns"] == {"obs_grad": 100, "obs_mix": 0, "obs_route": 80}
+    rows = sum(ns for _, sc, ns, _ in r["op_table"] if "obs_route" in sc)
+    assert rows == 50 + 15 + 20 + 30  # a sum of rows counts the body twice
+    facts = {"trace": r}
+    assert harness.read_metric("mix.ms", facts) is None
+    assert harness.read_metric("step.grad_ms", facts) == 100 / 2 / 1e6
+
+
+def test_the_recorded_traces_scopes_read_as_before():
+    r = trace_reduce.reduce(trace_reduce.load_json_events(
+        os.path.join(HERE, "testdata", "trace_events.json")))
+    assert r["scope_ns"] == {"obs_grad": 94542515, "obs_mix": 40833514}
 
 
 def test_too_few_step_executions_give_nothing():

@@ -5,7 +5,8 @@ keeps three kinds of event, each a dict ``{"name", "start", "dur", "scope"}``
 with times in nanoseconds on one clock:
 
 * ``ops``: the device's XLA operations (``scope`` is the operation's
-  name-scope path, which carries ``obs_grad`` / ``obs_mix``);
+  name-scope path, which carries the program's ``obs_`` scopes, such as
+  ``obs_grad`` and ``obs_mix``);
 * ``modules``: executions of whole XLA programs on the device;
 * ``host``: the host threads' events (Python calls, dispatch).
 
@@ -20,8 +21,11 @@ from __future__ import annotations
 import glob
 import json
 import os
+import re
 
+# read by metrics/, so present in every reduction (at 0 where absent)
 SCOPES = ("obs_grad", "obs_mix")
+_OBS = re.compile(r"\bobs_\w+")
 
 
 def _varint(buf, i):
@@ -187,8 +191,10 @@ def step_module(events: dict) -> str | None:
 
 def reduce(events: dict, *, top: int = 10) -> dict | None:
     """Per-window sums: ``steps`` whole steps in ``window_ns``; ``busy_ns``
-    (union of device ops); ``scope_ns``, the union of the ops whose
-    ``tf_op`` path holds each name scope; ``op_table``, each
+    (union of device ops); ``scope_ns``, for every name scope that starts
+    with ``obs_`` and appears in the window's ops (and for ``SCOPES``
+    always), the union of the ops whose ``tf_op`` path holds that name (a
+    scope whose name holds another's counts under both); ``op_table``, each
     op name with its scope, device time and count; the ``top`` ops by time
     and the ``top`` longest idle gaps, each named by the innermost host
     event around its middle. None when the trace holds fewer than two
@@ -205,8 +211,9 @@ def reduce(events: dict, *, top: int = 10) -> dict | None:
             ops.append((s, e, o))
     busy, merged = _union([(s, e) for s, e, _ in ops])
     # a union per scope: a loop's op and the ops of its body nest
+    names = set(SCOPES).union(*(_OBS.findall(o["scope"]) for _, _, o in ops))
     scope_ns = {sc: _union([(s, e) for s, e, o in ops if sc in o["scope"]])[0]
-                for sc in SCOPES}
+                for sc in sorted(names)}
     table = {}
     for s, e, o in ops:
         row = table.setdefault(o["name"], [o["scope"], 0, 0])
